@@ -1,14 +1,14 @@
 """qwen3-4b [dense] — qk_norm, GQA.
 
 36L d_model=2560 32H (GQA kv=8) d_ff=9728 vocab=151936.
-[hf:Qwen/Qwen3-8B; hf]
+[hf:Qwen/Qwen3-4B; hf]
 """
 from repro.configs.base import ModelConfig
 
 CONFIG = ModelConfig(
     name="qwen3-4b",
     family="dense",
-    source="hf:Qwen/Qwen3-8B",
+    source="hf:Qwen/Qwen3-4B",
     num_layers=36,
     d_model=2560,
     num_heads=32,

@@ -44,10 +44,10 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, positions,
     q: (B, 1, KV, G, D) — one query token per slot; k/v pools:
     (P, page, KV, D); page_table: (B, M) int32; positions: (B,) int32.
     Returns (B, 1, KV, G, D).  No gathered dense KV view is materialized:
-    each (slot, kv-head) program streams one physical page at a time
+    each slot's program streams one physical page at a time
     (``repro.kernels.paged_decode``).  ``k_scale``/``v_scale``
     ((P, page, KV) fp32, int8 pools): dequantized in-register inside the
-    kernel, scales SMEM-prefetched next to the page table."""
+    kernel, each page's scales loaded through the same page walk."""
     from repro.kernels import paged_decode as _pd
     b, s, kv, g, d = q.shape
     assert s == 1, q.shape

@@ -7,17 +7,23 @@ batch even though *pinned* pool bytes do not, which is exactly the
 memory-movement waste the serving story is trying to kill.  This kernel walks
 the indirection instead of materializing it:
 
-* Grid ``(B, KV, M)`` with the logical-page dimension innermost — TPU grids
-  iterate the trailing dim sequentially, so each (slot, kv-head) program
-  streams its slot's pages one block at a time while the online-softmax
-  running state (m, l, acc) lives in VMEM scratch across page steps.
+* Grid ``(B, M)`` with the logical-page dimension innermost — TPU grids
+  iterate the trailing dim sequentially, so each slot's program streams its
+  pages one block at a time while the online-softmax running state
+  (m, l, acc) lives in VMEM scratch across page steps.
+* One block is one whole physical page, ``(1, page, KV, D)``: its last two
+  dims are the pool's own, which is the tiling Mosaic accepts (a one-head
+  ``(1, page, 1, D)`` block is refused).  In the body the page is viewed as
+  ``page*KV`` key rows, every query head of the slot scores all of them in
+  one matmul, and rows of other KV heads are masked out — extra MXU work
+  that a memory-bound decode does not feel.
 * The **page table walk happens in the BlockSpec index maps** via scalar
   prefetch (``PrefetchScalarGridSpec``): the (B, M) table, (B,) position
   vector, and (1,) local-page offset are SMEM-resident before the body runs,
-  and the K/V index map for grid point (b, h, j) resolves physical page
-  ``table[b, j] - offset`` directly — the pipeline DMAs exactly one
-  (page, D) tile of each pool per step, so per-step transient memory is
-  O(block) = O(page·D), not O(B·M·page·D).
+  and the K/V index map for grid point (b, j) resolves physical page
+  ``table[b, j] - offset`` directly — the pipeline DMAs exactly one page of
+  each pool per step, so per-step transient memory is O(block) =
+  O(page·KV·D), not O(B·M·page·KV·D).
 * **Early exit**: pages wholly past the slot's position carry no live rows,
   and — under sharded serving — pages outside this chip's local window
   ``[offset, offset + P_local)`` belong to another chip's pool shard.  Both
@@ -51,12 +57,12 @@ Layouts (model code adapts via ``repro.kernels.ops``):
   positions:  (B,) int32      per-slot decode position (the row just written)
   out:        (B, KV, G, D)   — or (acc, l, m) when ``partials=True``
 
-Occupancy/shape assumptions (documented in ROADMAP): one program per
-(slot, kv-head) — B·KV programs — and the KV block equals one physical page,
-so TPU-efficient operation wants page·D tiles aligned to the (8, 128) fp32 /
-(16, 128) bf16 tiling (i.e. serve with page_size >= 8; tiny pages still run,
-they just underfill the MXU).  The page table, positions, and offset ride in
-SMEM: B·(M+1)+1 int32 scalars per dispatch.
+Shape assumptions: the page block wants page·KV key rows aligned to the
+(8, 128) fp32 tiling, i.e. serve with page_size >= 8 and head_dim a
+multiple of 128 (tiny test shapes still run in interpret mode).  The page
+table, positions, and offset ride in SMEM: B·(M+1)+1 int32 scalars per
+dispatch.  Int8 scales travel as VMEM blocks through the same page walk,
+so SMEM use does not grow with the pool.
 """
 from __future__ import annotations
 
@@ -71,21 +77,17 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _kernel(pt_ref, pos_ref, off_ref, *refs,
-            scale: float, page: int, n_pages: int, p_local: int,
-            partials: bool, quantized: bool):
+def _kernel(pt_ref, pos_ref, off_ref, q_ref, k_ref, v_ref, *refs,
+            scale: float, page: int, kv: int, g: int, n_pages: int,
+            p_local: int, partials: bool, quantized: bool):
     if quantized:
         ks_ref, vs_ref, *refs = refs
-    else:
-        ks_ref = vs_ref = None
-    q_ref, k_ref, v_ref, *refs = refs
     if partials:
         o_ref, l_ref, mx_ref, m_scr, l_scr, acc_scr = refs
     else:
         o_ref, m_scr, l_scr, acc_scr = refs
     b = pl.program_id(0)
-    h = pl.program_id(1)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -102,26 +104,30 @@ def _kernel(pt_ref, pos_ref, off_ref, *refs,
     # redirected to local page 0 by the index map); skip compute entirely
     @pl.when((j * page <= pos) & (local >= 0) & (local < p_local))
     def _body():
-        q = q_ref[0, 0].astype(jnp.float32) * scale            # (G, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)              # (page, D)
-        if quantized:
-            # in-register dequant: the (page,) fp32 scale rows for this
-            # (page, head) ride in SMEM next to the page table and resolve
-            # through the same ``local`` id the DMA used
-            k = k * ks_ref[local, :, h][:, None]
+        q = q_ref[0].astype(jnp.float32) * scale               # (KV*G, D)
+        # key row c of the page view is (page row c // KV, kv head c % KV)
+        k = k_ref[0].astype(jnp.float32).reshape(page * kv, -1)
+        v = v_ref[0].astype(jnp.float32).reshape(page * kv, -1)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        rows = j * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(rows <= pos, s, NEG_INF)                 # (G, page)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + p.sum(axis=-1)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        rq = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        ck = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        rows = j * page + ck // kv
+        live = (ck % kv == rq // g) & (rows <= pos)      # (KV*G, page*KV)
         if quantized:
-            v = v * vs_ref[local, :, h][:, None]
-        acc_scr[...] = acc_scr[...] * corr[:, None] + jax.lax.dot_general(
+            # in-register dequant: a key row's scale multiplies its score
+            # and a value row's scale its probability, so the int8 tile is
+            # never rescaled in full
+            s = s * ks_ref[0]
+        s = jnp.where(live, s, NEG_INF)
+        m_prev = m_scr[...]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.where(live, jnp.exp(s - m_new), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l_scr[...] = l_scr[...] * corr + p.sum(axis=-1, keepdims=True)
+        if quantized:
+            p = p * vs_ref[0]
+        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_scr[...] = m_new
@@ -131,12 +137,12 @@ def _kernel(pt_ref, pos_ref, off_ref, *refs,
         if partials:
             # raw online-softmax triple: the caller's cross-chip merge
             # normalizes once, after combining every chip's contribution
-            o_ref[0, 0] = acc_scr[...].astype(o_ref.dtype)
-            l_ref[0, 0] = l_scr[...]
-            mx_ref[0, 0] = m_scr[...]
+            o_ref[0] = acc_scr[...].astype(o_ref.dtype)
+            l_ref[0] = l_scr[...]
+            mx_ref[0] = m_scr[...]
         else:
-            denom = jnp.maximum(l_scr[...], 1e-30)[:, None]
-            o_ref[0, 0] = (acc_scr[...] / denom).astype(o_ref.dtype)
+            denom = jnp.maximum(l_scr[...], 1e-30)
+            o_ref[0] = (acc_scr[...] / denom).astype(o_ref.dtype)
 
 
 def paged_flash_decode(q, k_pool, v_pool, page_table, positions,
@@ -153,11 +159,10 @@ def paged_flash_decode(q, k_pool, v_pool, page_table, positions,
     output, for the cross-chip partial-softmax merge of sharded serving.
 
     ``k_scale``/``v_scale`` (int8 pools): (P, page, KV) fp32 absmax scales
-    for the quantized page format.  They ride as scalar-prefetch operands —
-    SMEM-resident next to the page table — and the body dequantizes each
-    K/V tile in-register (``int8 -> fp32 * scale_row``) right after the
-    block load, so the dense-precision transient never exists: HBM traffic
-    stays at the int8 tile plus (page,) scale rows per grid step."""
+    for the quantized page format.  Each page's ``page*KV`` scales ride as a
+    VMEM block resolved by the same page walk as the int8 tile, and the body
+    applies them to scores and probabilities, so HBM traffic stays at the
+    int8 tile plus one scale row per grid step."""
     b, kv, g, d = q.shape
     p_local, page = k_pool.shape[:2]
     assert k_pool.shape == v_pool.shape and k_pool.shape[2:] == (kv, d), (
@@ -170,60 +175,57 @@ def paged_flash_decode(q, k_pool, v_pool, page_table, positions,
     if quantized:
         assert k_scale.shape == v_scale.shape == (p_local, page, kv), (
             k_scale.shape, k_pool.shape)
-    scale = 1.0 / math.sqrt(d)
+    r, c = kv * g, page * kv
     if page_offset is None:
         page_offset = 0
     off = jnp.asarray(page_offset, jnp.int32).reshape(1)
 
-    # index maps see every scalar-prefetch operand; scales (when present)
-    # trail the table/positions/offset and are unused for indexing
-    def q_map(b_, h, j, pt, pos, off, *_):
-        return (b_, h, 0, 0)
+    def row_map(b_, j, pt, pos, off):
+        return (b_, 0, 0)
 
-    def lm_map(b_, h, j, pt, pos, off, *_):
-        return (b_, h, 0)
-
-    def kv_map(b_, h, j, pt, pos, off, *_):
+    def page_map(b_, j, pt, pos, off):
         # the page-table walk: dead pages (past the slot's position) and
         # non-local pages (outside this chip's pool shard) resolve to local
         # page 0 so repeated skipped steps elide their DMA
         local = pt[b_, j] - off[0]
         ok = (j * page <= pos[b_]) & (local >= 0) & (local < p_local)
-        return (jnp.where(ok, local, 0), 0, h, 0)
+        return (jnp.where(ok, local, 0), 0, 0, 0)
 
-    kernel = functools.partial(_kernel, scale=scale, page=page, n_pages=m,
-                               p_local=p_local, partials=partials,
-                               quantized=quantized)
-    out_specs = [pl.BlockSpec((1, 1, g, d), q_map)]
-    out_shape = [jax.ShapeDtypeStruct(
-        (b, kv, g, d), jnp.float32 if partials else q.dtype)]
-    if partials:
-        out_specs += [pl.BlockSpec((1, 1, g), lm_map),
-                      pl.BlockSpec((1, 1, g), lm_map)]
-        out_shape += [jax.ShapeDtypeStruct((b, kv, g), jnp.float32),
-                      jax.ShapeDtypeStruct((b, kv, g), jnp.float32)]
-    scalar_args = [page_table.astype(jnp.int32),
-                   positions.astype(jnp.int32), off]
+    def scale_map(b_, j, pt, pos, off):
+        return page_map(b_, j, pt, pos, off)[:3]
+
+    in_specs = [pl.BlockSpec((1, r, d), row_map),
+                pl.BlockSpec((1, page, kv, d), page_map),
+                pl.BlockSpec((1, page, kv, d), page_map)]
+    operands = [q.reshape(b, r, d), k_pool, v_pool]
     if quantized:
-        scalar_args += [k_scale.astype(jnp.float32),
-                        v_scale.astype(jnp.float32)]
+        in_specs += [pl.BlockSpec((1, 1, c), scale_map)] * 2
+        operands += [s.astype(jnp.float32).reshape(p_local, 1, c)
+                     for s in (k_scale, v_scale)]
+    out_specs = [pl.BlockSpec((1, r, d), row_map)]
+    out_shape = [jax.ShapeDtypeStruct(
+        (b, r, d), jnp.float32 if partials else q.dtype)]
+    if partials:
+        out_specs += [pl.BlockSpec((1, r, 1), row_map)] * 2
+        out_shape += [jax.ShapeDtypeStruct((b, r, 1), jnp.float32)] * 2
+    kernel = functools.partial(
+        _kernel, scale=1.0 / math.sqrt(d), page=page, kv=kv, g=g,
+        n_pages=m, p_local=p_local, partials=partials, quantized=quantized)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(scalar_args),
-        grid=(b, kv, m),
-        in_specs=[
-            pl.BlockSpec((1, 1, g, d), q_map),
-            pl.BlockSpec((1, page, 1, d), kv_map),
-            pl.BlockSpec((1, page, 1, d), kv_map),
-        ],
+        num_scalar_prefetch=3, grid=(b, m),
+        in_specs=in_specs,
         out_specs=out_specs if partials else out_specs[0],
-        scratch_shapes=[
-            pltpu.VMEM((g,), jnp.float32),       # running max
-            pltpu.VMEM((g,), jnp.float32),       # running sum
-            pltpu.VMEM((g, d), jnp.float32),     # output accumulator
-        ],
-    )
-    return pl.pallas_call(
+        scratch_shapes=[pltpu.VMEM((r, 1), jnp.float32),
+                        pltpu.VMEM((r, 1), jnp.float32),
+                        pltpu.VMEM((r, d), jnp.float32)])
+    out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=out_shape if partials else out_shape[0],
         interpret=interpret,
-    )(*scalar_args, q, k_pool, v_pool)
+    )(page_table.astype(jnp.int32), positions.astype(jnp.int32), off,
+      *operands)
+    if not partials:
+        return out.reshape(b, kv, g, d)
+    acc, l, mx = out
+    return (acc.reshape(b, kv, g, d), l.reshape(b, kv, g),
+            mx.reshape(b, kv, g))

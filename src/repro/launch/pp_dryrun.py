@@ -31,7 +31,7 @@ from repro.models.common import apply_norm
 from repro.parallel.mesh import make_mesh, make_production_mesh
 from repro.parallel.pipeline import pipeline_forward
 from repro.parallel.sharding import default_rules, logical_to_sharding, \
-    shard_map, sharding_context
+    sharding_context
 from repro.roofline.hlo import parse_collectives
 
 OUT_DIR = Path(__file__).resolve().parents[3] / "experiments" / "dryrun"
@@ -71,7 +71,7 @@ def build_pp_forward(lm: LM, cfg, mesh, rules, opts, n_microbatches: int):
         spec_params = jax.tree.map(lambda _: P("pod"), params["layers"])
         # fully manual: pipeline over pod, batch over data (microbatch dim
         # replicated; the per-microbatch batch dim is data-sharded)
-        fn = shard_map(
+        fn = jax.shard_map(
             inner, mesh=mesh,
             in_specs=(spec_params, P(None, "data", None, None)),
             out_specs=P(None, "data", None, None),

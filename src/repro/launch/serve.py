@@ -7,6 +7,7 @@ group prefill, on-device sampling, and a pluggable KV cache — paged
 contiguous dense rows via ``--cache-backend contiguous``.
 
     python -m repro.launch.serve --arch qwen3-4b --reduced --requests 16
+    python -m repro.launch.serve --max-seq 1024   # published widths, bf16
     python -m repro.launch.serve --cache-backend paged --page-size 8 \
         --num-pages 48   # tight pool: watch admissions defer, not OOM
     python -m repro.launch.serve --decode-impl pallas   # page-table-walking
@@ -44,12 +45,15 @@ contiguous dense rows via ``--cache-backend contiguous``.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.configs import CONFIGS, get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import LM
 from repro.serve import Request, SamplingParams, ServeEngine
 
@@ -72,10 +76,13 @@ def _parse_mesh(spec: str):
     return 0, int(spec)
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-4b", choices=sorted(CONFIGS))
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the config's reduced() twin in float32 "
+                         "(CPU-sized); default: the published widths with "
+                         "weights in the compute dtype")
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--max-batch", type=int, default=4)
     ap.add_argument("--max-seq", type=int, default=128)
@@ -202,28 +209,74 @@ def main():
                          "pass admits against a warm host tier, showing "
                          "cross-engine prefix persistence.  Requires "
                          "--host-pages")
-    args = ap.parse_args()
-    if args.prefix_store and not args.host_pages:
-        raise SystemExit("--prefix-store persists the host tier across "
-                         "engines; size it with --host-pages N")
+    return ap
 
-    import dataclasses
+
+def build_lm(args) -> LM:
+    """The model for ``--arch``.  Serving holds no master weights, so
+    parameters live in the compute dtype (bf16 at published widths: 8 GB
+    for qwen3-4b, which fits one 16 GB chip with room for KV pages);
+    ``--reduced`` serves the float32 reduced() twin."""
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = dataclasses.replace(cfg.reduced(), dtype="float32")
-    lm = LM(cfg)
-    params = lm.init(jax.random.key(0))
-    mesh, dp_axis = None, None
+    return LM(dataclasses.replace(cfg, param_dtype=cfg.dtype))
+
+
+def build_model(args, mesh=None):
+    """(cfg, lm, params) with random weights from a fixed seed.  Under a
+    ``mesh`` the weights are made replicated on every chip of it, so no
+    dispatch copies them."""
+    lm = build_lm(args)
+    init = jax.jit(lm.init, out_shardings=(
+        None if mesh is None else NamedSharding(mesh, PartitionSpec())))
+    return lm.cfg, lm, init(jax.random.key(0))
+
+
+def build_mesh(args, devices=None):
+    """(mesh, dp_axis) for ``--mesh``; (None, None) when single-device."""
     dp, nkv = _parse_mesh(args.mesh)
-    if nkv:
-        from repro.parallel.mesh import make_mesh
-        if dp:
-            # 2-D batch x pages mesh: dp axis named 'data' (matching the
-            # batch sharding rule in repro.parallel.sharding)
-            mesh = make_mesh((dp, nkv), ("data", args.mesh_axis))
-            dp_axis = "data"
-        else:
-            mesh = make_mesh((nkv,), (args.mesh_axis,))
+    if not nkv:
+        return None, None
+    from repro.parallel.mesh import make_mesh
+    if dp:
+        # 2-D batch x pages mesh: dp axis named 'data' (matching the
+        # batch sharding rule in repro.parallel.sharding)
+        return make_mesh((dp, nkv), ("data", args.mesh_axis),
+                         devices), "data"
+    return make_mesh((nkv,), (args.mesh_axis,), devices), None
+
+
+def build_engine(lm, params, args, *, mesh=None, dp_axis=None,
+                 registry=None, tenancy=None, fault_plan=None, alerts=None,
+                 prefix_store=None) -> ServeEngine:
+    """The ServeEngine the command line describes."""
+    return ServeEngine(lm, params, args.max_batch, args.max_seq,
+                       registry=registry,
+                       cache_backend=args.cache_backend,
+                       page_size=args.page_size,
+                       num_pages=args.num_pages,
+                       prefix_sharing=not args.no_prefix_sharing,
+                       decode_impl=args.decode_impl, mesh=mesh,
+                       kv_axis=args.mesh_axis, dp_axis=dp_axis,
+                       prefill_chunk=args.prefill_chunk,
+                       prefill_budget=args.prefill_budget,
+                       kv_dtype=args.kv_dtype, tenancy=tenancy,
+                       fault_plan=fault_plan,
+                       watchdog_iters=args.watchdog_iters,
+                       max_retries=args.max_retries,
+                       verify_cache=args.verify_cache,
+                       alerts=alerts, prefix_store=prefix_store)
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    if args.prefix_store and not args.host_pages:
+        raise SystemExit("--prefix-store persists the host tier across "
+                         "engines; size it with --host-pages N")
+    enable_compile_cache()
+    mesh, dp_axis = build_mesh(args)
+    cfg, lm, params = build_model(args, mesh)
     tenancy = None
     if args.tenants:
         from repro.serve import TenancyConfig
@@ -247,24 +300,12 @@ def main():
         from repro.serve import PrefixStore
         store = PrefixStore(args.host_pages)
 
-    def build_engine(registry, with_alerts=True):
-        return ServeEngine(lm, params, args.max_batch, args.max_seq,
-                           registry=registry,
-                           cache_backend=args.cache_backend,
-                           page_size=args.page_size,
-                           num_pages=args.num_pages,
-                           prefix_sharing=not args.no_prefix_sharing,
-                           decode_impl=args.decode_impl, mesh=mesh,
-                           kv_axis=args.mesh_axis, dp_axis=dp_axis,
-                           prefill_chunk=args.prefill_chunk,
-                           prefill_budget=args.prefill_budget,
-                           kv_dtype=args.kv_dtype, tenancy=tenancy,
-                           fault_plan=fault_plan,
-                           watchdog_iters=args.watchdog_iters,
-                           max_retries=args.max_retries,
-                           verify_cache=args.verify_cache,
-                           alerts=alerts if with_alerts else None,
-                           prefix_store=store)
+    def engine_for(registry, with_alerts=True):
+        return build_engine(lm, params, args, mesh=mesh, dp_axis=dp_axis,
+                            registry=registry, tenancy=tenancy,
+                            fault_plan=fault_plan,
+                            alerts=alerts if with_alerts else None,
+                            prefix_store=store)
 
     tenant_names = sorted(tenancy.tenants) if tenancy else []
 
@@ -284,7 +325,7 @@ def main():
     if args.prefix_store:
         # warmup engine: same workload, own registry, SAME store — its
         # freed prefixes offload to host and survive the engine teardown
-        warm = build_engine(MetricsRegistry(), with_alerts=False)
+        warm = engine_for(MetricsRegistry(), with_alerts=False)
         t0 = time.perf_counter()
         submit_all(warm)
         warm.run_until_drained(on_stuck="status")
@@ -295,7 +336,7 @@ def main():
               f"now host-resident; rebuilding engine on the warm store")
         del warm
 
-    eng = build_engine(reg)
+    eng = engine_for(reg)
     t0 = time.perf_counter()
     submit_all(eng)
     done = eng.run_until_drained(on_stuck="status")
@@ -410,6 +451,11 @@ def main():
         print("alerts fired:")
         for m in slack.messages:
             print(f"  {m}")
+    short = [r for r in done if r.status != "completed"]
+    if short:
+        raise SystemExit(f"{len(short)} of {len(done)} requests did not "
+                         f"complete: " + ", ".join(
+                             f"{r.id} [{r.status}]" for r in short))
 
 
 if __name__ == "__main__":

@@ -21,8 +21,12 @@ from repro.core import (CheckpointManager, FTTrainLoop, MetricsRegistry,
                         job_mtbf_seconds)
 from repro.data import (DeterministicLoader, LoaderConfig, TokenDataset,
                         synthetic_corpus, write_token_shards)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import LM, ForwardOpts
 from repro.train import init_train_state, make_train_step
+
+# run artifacts stay inside the checkout, in its git-ignored experiments/
+RUN_DIR = Path(__file__).resolve().parents[3] / "experiments"
 
 QUICKSTART_100M = ModelConfig(
     name="quickstart-100m", family="dense", num_layers=12, d_model=768,
@@ -47,12 +51,13 @@ def main():
     ap.add_argument("--seq", type=int, default=256)
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--microbatches", type=int, default=1)
-    ap.add_argument("--data-dir", default="/tmp/repro_data")
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
+    ap.add_argument("--data-dir", default=str(RUN_DIR / "data"))
+    ap.add_argument("--ckpt-dir", default=str(RUN_DIR / "ckpt"))
     ap.add_argument("--ckpt-every", type=int, default=0,
                     help="0 = Young's formula from measured step time")
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = build_config(args)
     print(f"arch={cfg.name} params={cfg.param_count()/1e6:.1f}M "

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.kernels.quant import dequantize, quantize_int8  # noqa: F401  (re-export: the int8 wire format lives in repro.kernels.quant, shared with the quantized KV page path)
-from repro.parallel.sharding import shard_map
 
 
 def compressed_allreduce_mean(x, err, *, axis: str):
@@ -40,7 +39,7 @@ def make_compressed_grad_sync(mesh: Mesh, axis: str):
     device partial gradients (pure-DP layout)."""
 
     def one(g, e):
-        fn = shard_map(
+        fn = jax.shard_map(
             partial(compressed_allreduce_mean, axis=axis),
             mesh=mesh, in_specs=(P(axis), P(axis)),
             out_specs=(P(), P(axis)), check_vma=False)

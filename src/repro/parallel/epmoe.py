@@ -28,7 +28,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from repro.models.common import activation
-from repro.parallel.sharding import current_context, shard_map
+from repro.parallel.sharding import current_context
 from repro.parallel.tpmm import TP_SAVE_NAME
 
 
@@ -155,7 +155,7 @@ def moe_ffn_ep(p, cfg, x, axis: str = "model"):
                    P(axis, "data" if data_ok else None)) if dense_ok else \
         (P(), P(), P())
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp, None, None), P(None, None), wspec, wspec,
                   P(axis, None, "data" if data_ok else None)) + dense_specs,

@@ -54,7 +54,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.parallel.mesh import mesh_axis_size
-from repro.parallel.sharding import default_rules, shard_map, spec_for
+from repro.parallel.sharding import default_rules, spec_for
 
 # logical axes of a per-layer-stacked page pool (L, P, page, KV, D); only
 # kv_pages resolves to a mesh axis — the page/head/dim axes stay local so
@@ -216,14 +216,14 @@ def sharded_paged_decode_attention(mesh, axis: str, q, k_new, v_new,
     bsp = PartitionSpec(dp) if dp is not None else PartitionSpec()
     sh = PartitionSpec(axis)
     if quantized:
-        fn = shard_map(body_quant, mesh=mesh,
-                       in_specs=(bsp, bsp, bsp, bsp, bsp, sh, sh, sh, sh),
-                       out_specs=(bsp, sh, sh, sh, sh), check_vma=False)
+        fn = jax.shard_map(body_quant, mesh=mesh,
+                           in_specs=(bsp, bsp, bsp, bsp, bsp, sh, sh, sh, sh),
+                           out_specs=(bsp, sh, sh, sh, sh), check_vma=False)
         return fn(q, k_new, v_new, page_table, positions, k_pool, v_pool,
                   k_scale, v_scale)
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(bsp, bsp, bsp, bsp, bsp, sh, sh),
-                   out_specs=(bsp, sh, sh), check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(bsp, bsp, bsp, bsp, bsp, sh, sh),
+                       out_specs=(bsp, sh, sh), check_vma=False)
     return fn(q, k_new, v_new, page_table, positions, k_pool, v_pool)
 
 
@@ -270,9 +270,9 @@ def sharded_write_prefill(mesh, axis: str, layers, kv_block, dest):
 
     sh = jax.tree.map(lambda _: PartitionSpec(None, axis), layers)
     rep = jax.tree.map(lambda _: PartitionSpec(), kv_block)
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(sh, rep, PartitionSpec()),
-                   out_specs=sh, check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(sh, rep, PartitionSpec()),
+                       out_specs=sh, check_vma=False)
     return fn(layers, kv_block, dest)
 
 
@@ -340,7 +340,7 @@ def sharded_prefill_chunk_attention(mesh, axis: str, q, k_new, v_new, dest,
     rep = PartitionSpec()
     sh = PartitionSpec(axis)
     if quantized:
-        fn = shard_map(
+        fn = jax.shard_map(
             body_quant, mesh=mesh,
             in_specs=(bsp, rep, rep, rep, rep, rep, bsp, bsp, bsp,
                       sh, sh, sh, sh),
@@ -348,8 +348,8 @@ def sharded_prefill_chunk_attention(mesh, axis: str, q, k_new, v_new, dest,
         return fn(q, k_new, v_new, k_scale_new, v_scale_new, dest,
                   page_table, start_pos, last_pos, k_pool, v_pool,
                   k_scale, v_scale)
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(bsp, rep, rep, rep, bsp, bsp, bsp, sh, sh),
-                   out_specs=(bsp, sh, sh), check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(bsp, rep, rep, rep, bsp, bsp, bsp, sh, sh),
+                       out_specs=(bsp, sh, sh), check_vma=False)
     return fn(q, k_new, v_new, dest, page_table, start_pos, last_pos,
               k_pool, v_pool)

@@ -20,8 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.parallel.sharding import shard_map
-
 
 def pipeline_forward(stage_fn: Callable, n_stages: int, axis: str):
     """Returns fn(stage_params, x_microbatches) for use INSIDE shard_map.
@@ -101,7 +99,7 @@ def make_pipelined_apply(layer_fn: Callable, mesh: Mesh, axis: str,
             return jax.lax.psum(out, axis)
 
         spec_params = jax.tree.map(lambda _: P(axis), params)
-        fn = shard_map(inner, mesh=mesh,
+        fn = jax.shard_map(inner, mesh=mesh,
                            in_specs=(spec_params, P()),
                            out_specs=P(),
                            check_vma=False)

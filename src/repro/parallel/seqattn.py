@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.parallel.sharding import current_context, shard_map
+from repro.parallel.sharding import current_context
 
 NEG_INF = -1e30
 
@@ -94,6 +94,6 @@ def seq_sharded_attention(q, k, v, *, axis: str = "model",
         return _blockwise_dyn_offset(ql, kf, vf, offset,
                                      min(q_chunk, s_loc), kv_chunk)
 
-    fn = shard_map(body, mesh=mesh, in_specs=(qspec, kvspec, kvspec),
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(qspec, kvspec, kvspec),
                        out_specs=qspec, check_vma=False)
     return fn(q, k, v)

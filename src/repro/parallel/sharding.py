@@ -18,19 +18,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-# jax moved shard_map out of experimental in 0.5.x and renamed check_rep to
-# check_vma; support both so the parallel modules run on the baked-in
-# toolchain
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:                                  # pragma: no cover - version dependent
-    from jax.experimental.shard_map import shard_map as _shard_map_compat
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True, **kw):
-        return _shard_map_compat(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=check_vma,
-                                 **kw)
-
 AxisVal = Union[None, str, Tuple[str, ...]]
 Rules = Dict[str, AxisVal]
 

@@ -23,7 +23,7 @@ from jax.sharding import PartitionSpec as P
 
 from jax.ad_checkpoint import checkpoint_name
 
-from repro.parallel.sharding import current_context, shard_map
+from repro.parallel.sharding import current_context
 
 TP_SAVE_NAME = "tp_psum_out"   # remat policy saves these (§Perf llama it6):
 # jax.checkpoint can't see inside shard_map, so without the name the psum'd
@@ -60,7 +60,7 @@ def o_proj_tp(y, kernel, bias=None, axis: str = "model"):
         part = jnp.einsum("bshe,hed->bsd", y_loc, w_loc.astype(dtype))
         return jax.lax.psum(part.astype(dtype), axis)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp, None, axis, None),
                   P(axis, None, "data" if data_ok else None)),
@@ -93,7 +93,7 @@ def col_proj_tp(x, kernel, bias=None, axis: str = "model"):
     w_spec = P("data" if data_ok else None, axis, None) if rank3 else \
         P("data" if data_ok else None, axis)
     out_spec = P(dp, None, axis, None) if rank3 else P(dp, None, axis)
-    fn = shard_map(body, mesh=mesh,
+    fn = jax.shard_map(body, mesh=mesh,
                        in_specs=(P(dp, None, None), w_spec),
                        out_specs=out_spec, check_vma=False)
     out = checkpoint_name(fn(x, kernel), TP_SAVE_NAME)
@@ -129,7 +129,7 @@ def down_proj_tp(h, kernel, bias=None, axis: str = "model"):
         part = jnp.einsum("bsf,fd->bsd", h_loc, w_loc.astype(dtype))
         return jax.lax.psum(part.astype(dtype), axis)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp, None, axis),
                   P(axis, "data" if data_ok else None)),

@@ -806,6 +806,23 @@ class ServeEngine:
             if self._iter - last >= self.watchdog_iters:
                 self._recover(slot, "watchdog")
 
+    def lower_decode(self, abstract=None):
+        """Lower the all-greedy fused decode+sample step at this engine's
+        shapes, to inspect what the device runs (``.compile().as_text()``
+        names the paged kernel as a ``tpu_custom_call`` on the TPU).
+        ``abstract`` maps every argument leaf to a stand-in, e.g. a
+        ShapeDtypeStruct placed on a described device, before lowering."""
+        view = self.kv.decode_view()
+        z = lambda dt: jnp.zeros(self.B, dt)                  # noqa: E731
+        args = (self.params, jnp.zeros((self.B, 1), jnp.int32),
+                view["layers"], view.get("page_table"), z(jnp.int32),
+                z(bool), z(jnp.float32), z(jnp.int32),
+                jnp.ones(self.B, jnp.float32), z(jnp.int32), z(jnp.int32),
+                z(bool))
+        if abstract is not None:
+            args = jax.tree.map(abstract, args)
+        return self._fused.lower(*args, True)
+
     def _dispatch_fused(self, *args):
         """The fused dispatch behind the transient-fault retry loop.  An
         injected :class:`TransientDispatchError` raises *before* the real

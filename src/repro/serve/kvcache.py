@@ -94,14 +94,14 @@ def decode_transient_bytes(cfg, batch: int, max_pages: int, page_size: int,
 
     ``"gather"``: XLA materializes two dense-equivalent gathered views,
     (B, M*page, KV, D) each — the transient grows with the paged-enlarged
-    concurrent batch.  ``"pallas"``: each (slot, kv-head) program of the
-    page-table-walking kernel streams one (page, D) K and V tile into VMEM
-    plus fp32 online-softmax state — O(page), independent of B and M.
+    concurrent batch.  ``"pallas"``: each slot's program of the
+    page-table-walking kernel streams one (page, KV, D) K and V page into
+    VMEM plus fp32 online-softmax state — O(page), independent of B and M.
 
     ``kv_dtype="int8"``: the gather twin additionally materializes the
     gathered scale views and the dequantized compute-dtype K/V (the int8
     gather shrinks but the dequant expands to ``dtype``); the kernel
-    streams the int8 tile + its (page,) scale rows and dequantizes
+    streams the int8 page + its (page, KV) scales and dequantizes
     in-register, so its transient *shrinks* with the narrow wire format."""
     itemsize = jnp.dtype(dtype).itemsize
     kvh, hd = cfg.num_kv_heads, cfg.resolved_head_dim
@@ -111,10 +111,10 @@ def decode_transient_bytes(cfg, batch: int, max_pages: int, page_size: int,
             return rows * (hd + SCALE_BYTES) + rows * hd * itemsize
         return rows * hd * itemsize
     assert decode_impl == "pallas", decode_impl
-    g = cfg.num_heads // kvh
+    rows, state = 2 * page_size * kvh, 4 * cfg.num_heads * (hd + 2)
     if kv_dtype == "int8":
-        return 2 * page_size * (hd + SCALE_BYTES) + 4 * g * (hd + 2)
-    return 2 * page_size * hd * itemsize + 4 * g * (hd + 2)
+        return rows * (hd + SCALE_BYTES) + state
+    return rows * hd * itemsize + state
 
 
 def prefill_transient_bytes(cfg, group: int, block_len: int, dtype,
@@ -685,10 +685,7 @@ class PagedCache:
         slices = {}
         for name in self.state["layers"]:
             a = self.state["layers"][name][:, pid]
-            try:
-                a.copy_to_host_async()
-            except (AttributeError, RuntimeError):
-                pass               # backend without async D2H: drain copies
+            a.copy_to_host_async()
             slices[name] = a
         self._pending_offload.append((key, slices))
         self._pending_keys.add(key)

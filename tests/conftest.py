@@ -12,8 +12,11 @@ sys.path.insert(0, str(SRC))
 
 def run_subprocess(code: str, devices: int = 8, timeout: int = 480):
     """Run a python snippet in a fresh process with N host devices (for
-    multi-device tests — the main pytest process keeps 1 device)."""
+    multi-device tests — the main pytest process keeps 1 device).  The
+    child is held to the CPU: the virtual devices are host devices, and a
+    parent that holds an accelerator would otherwise starve it."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = str(SRC)
     res = subprocess.run([sys.executable, "-c", code], capture_output=True,

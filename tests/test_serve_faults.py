@@ -500,3 +500,20 @@ def test_nan_guard_recovers_on_contiguous_backend(model):
     assert _drain(eng, _requests(cfg)) == base
     assert eng.reg.counter("serve_stream_retries_total").get(
         {"reason": "nonfinite_logits"}) >= 1
+
+
+def test_serve_cli_exits_nonzero_when_a_request_does_not_complete(
+        capsys, monkeypatch):
+    """The serving entry point reports per-stream outcomes, then fails the
+    process when any request ended short of ``completed``."""
+    from repro.launch import serve
+
+    # leave this worker's JAX compile-cache settings as they were
+    monkeypatch.setattr(serve, "enable_compile_cache", lambda: None)
+    with pytest.raises(SystemExit) as e:
+        serve.main(["--reduced", "--requests", "3", "--max-batch", "2",
+                    "--max-seq", "64", "--new-tokens", "6",
+                    "--fault-plan", "nan_logits@2:slot=0",
+                    "--max-retries", "0"])
+    assert "did not complete: 0 [dead_letter]" in str(e.value.code)
+    assert "request 0 [dead_letter]" in capsys.readouterr().out
