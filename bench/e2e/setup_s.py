@@ -1,0 +1,7 @@
+"""Set-up seconds: process start to the window's opening (loading the
+program, drawing the weights, building the engine, obtaining and warming
+its step programs)."""
+
+
+def read(run):
+    return run.setup_s
