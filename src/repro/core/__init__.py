@@ -16,7 +16,7 @@ from repro.core.scheduler import GangScheduler, Job, JobState
 from repro.core.storage import (COS, NFS, SCALE, BlobStore, ScaleCache,
                                 StorageStack)
 from repro.core.straggler import StragglerDetector
-from repro.core.telemetry import GLOBAL_REGISTRY, MetricsRegistry
+from repro.core.telemetry import MetricsRegistry, SpanLog
 from repro.core.youngs import (checkpoint_every_n_steps, lost_fraction,
                                optimal_lost_fraction, young_interval)
 
@@ -28,7 +28,7 @@ __all__ = [
     "FailureKind", "Node", "NodeState", "SimCluster", "Autopilot",
     "FTTrainLoop", "GoodputReport", "job_mtbf_seconds", "simulate_job",
     "GangScheduler", "Job", "JobState", "COS", "NFS", "SCALE", "BlobStore",
-    "ScaleCache", "StorageStack", "StragglerDetector", "GLOBAL_REGISTRY",
-    "MetricsRegistry", "checkpoint_every_n_steps", "lost_fraction",
+    "ScaleCache", "StorageStack", "StragglerDetector",
+    "MetricsRegistry", "SpanLog", "checkpoint_every_n_steps", "lost_fraction",
     "optimal_lost_fraction", "young_interval",
 ]

@@ -1,11 +1,16 @@
 """Prometheus-style metrics registry (counters / gauges / histograms) — the
 observability surface of §2.3.2 / §3.2.3.  Pure python, thread-safe, with a
-text exposition renderer for the dashboards in the examples."""
+text exposition renderer for the dashboards in the examples — and, beside
+it, ``SpanLog``: the host spans of a program's phases, on the clock the
+program's callers time requests with and on the profiler's."""
 from __future__ import annotations
 
 import bisect
+import itertools
 import threading
-from typing import Dict, Iterable, List, Optional, Tuple
+import time
+from collections import deque
+from typing import Deque, Dict, Iterable, List, NamedTuple, Optional, Tuple
 
 LabelSet = Tuple[Tuple[str, str], ...]
 
@@ -137,4 +142,76 @@ class MetricsRegistry:
         return "\n".join(lines) + "\n"
 
 
-GLOBAL_REGISTRY = MetricsRegistry()
+class Span(NamedTuple):
+    id: int
+    name: str
+    start: float                     # time.perf_counter() seconds
+    end: float
+    parent_id: Optional[int]         # the span open around it, if any
+    request_id: Optional[int]
+
+
+class SpanLog:
+    """The last ``CAPACITY`` host spans of one program (or of several that
+    share the log), in memory.
+
+    ``span(name)`` times a block on ``time.perf_counter()``: its parent is
+    the span open around it (callers are single-threaded, so a stack), and
+    it opens a ``jax.profiler.TraceAnnotation`` of the same name, which
+    puts it in a profiler trace beside the device's ops.  ``record`` logs
+    a span that has already ended, such as a request's wait in a queue;
+    the profiler cannot take one after the fact, so it stays out of the
+    trace.  No device work, no sync, no logging."""
+
+    CAPACITY = 65_536
+
+    def __init__(self):
+        self._ring: Deque[Span] = deque(maxlen=self.CAPACITY)
+        self._open: List[int] = []
+        self._ids = itertools.count()
+        self._annotation = None          # TraceAnnotation, imported lazily
+
+    def span(self, name: str, request_id: Optional[int] = None
+             ) -> "_OpenSpan":
+        if self._annotation is None:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
+        return _OpenSpan(self, name, request_id)
+
+    def record(self, name: str, start: float, end: float,
+               request_id: Optional[int] = None) -> None:
+        self._ring.append(Span(next(self._ids), name, start, end,
+                               self._open[-1] if self._open else None,
+                               request_id))
+
+    def spans(self, name: Optional[str] = None) -> List[Span]:
+        """The spans kept, in the order they ended."""
+        return [s for s in self._ring if name is None or s.name == name]
+
+    def __len__(self) -> int:
+        return len(self._ring)
+
+
+class _OpenSpan:
+    __slots__ = ("log", "name", "request_id", "id", "start", "ann")
+
+    def __init__(self, log: SpanLog, name: str, request_id: Optional[int]):
+        self.log, self.name, self.request_id = log, name, request_id
+
+    def __enter__(self) -> "_OpenSpan":
+        log = self.log
+        self.ann = log._annotation(self.name)
+        self.ann.__enter__()
+        self.id = next(log._ids)
+        log._open.append(self.id)
+        self.start = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        end = time.perf_counter()
+        log = self.log
+        log._open.pop()
+        log._ring.append(Span(self.id, self.name, self.start, end,
+                              log._open[-1] if log._open else None,
+                              self.request_id))
+        self.ann.__exit__(*exc)

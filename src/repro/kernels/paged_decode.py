@@ -221,7 +221,7 @@ def paged_flash_decode(q, k_pool, v_pool, page_table, positions,
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=out_shape if partials else out_shape[0],
-        interpret=interpret,
+        interpret=interpret, name="paged_decode",
     )(page_table.astype(jnp.int32), positions.astype(jnp.int32), off,
       *operands)
     if not partials:

@@ -106,7 +106,12 @@ memory accounting are exported through the metrics registry
 ``serve_prefill_dispatches_total`` / ``serve_prefill_batch_size`` /
 ``serve_kv_pages_in_use`` / ``serve_kv_bytes_reserved`` /
 ``serve_decode_transient_bytes``) so the one-call-per-iteration and
-paged-memory invariants are observable, not asserted.
+paged-memory invariants are observable, not asserted.  The phases of each
+``step()`` — prefill rounds and their waits, admission, each request's
+queue wait, the decode dispatch, the wait on its sampled tokens, the emit
+— are timed as host spans (``serve.*``) in the engine's ``SpanLog``
+(``spans=``), on ``time.perf_counter()`` and in any profiler trace; the
+table is in ``docs/telemetry.md``.
 """
 from __future__ import annotations
 
@@ -119,7 +124,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models import ForwardOpts, LM
-from repro.core.telemetry import MetricsRegistry
+from repro.core.telemetry import MetricsRegistry, SpanLog
 from repro.serve.faults import FaultEvent, FaultPlan, TransientDispatchError
 from repro.serve.sampling import sample_batch
 from repro.serve.tenancy import TenancyConfig, Victim, next_victim
@@ -226,7 +231,8 @@ class ServeEngine:
                  verify_cache: bool = False, alerts=None,
                  health_every: int = 16,
                  locality_chips: Optional[int] = None,
-                 host_pages: int = 0, prefix_store=None):
+                 host_pages: int = 0, prefix_store=None,
+                 spans: Optional[SpanLog] = None):
         # per-slot positions rely on masked-then-overwritten cache writes,
         # which holds for attention KV caches but not recurrent state
         assert lm.cfg.family in ("dense", "moe", "vlm"), (
@@ -239,6 +245,8 @@ class ServeEngine:
         self.finished: List[Request] = []
         self.opts = opts
         self.reg = registry or MetricsRegistry()
+        self.spans = spans if spans is not None else SpanLog()
+        self._span = self.spans.span
         self.greedy = greedy
         self.img_len = (lm.cfg.num_image_tokens
                         if lm.cfg.family == "vlm" else 0)
@@ -363,6 +371,16 @@ class ServeEngine:
             self._chunk_step = jax.jit(self._make_chunk(),
                                        donate_argnums=(2,))
         self._declare_metrics()
+        if type(self.kv).backend == "paged":
+            # per-step transient of the paged KV read path (byte math, one
+            # layer): the gather fallback scales with B·M·page, the pallas
+            # kernel with the page block only — dense rows gather nothing
+            from repro.serve.kvcache import decode_transient_bytes
+            self.reg.gauge("serve_decode_transient_bytes").set(
+                decode_transient_bytes(
+                    lm.cfg, self.B, self.kv.max_pages, self.kv.page,
+                    self.kv.dtype, self.kv.decode_impl,
+                    kv_dtype=self.kv.kv_dtype))
         if tenancy is not None:
             for spec in tenancy.tenants.values():
                 if spec.page_quota is not None:
@@ -516,8 +534,8 @@ class ServeEngine:
         has_img = self.img_len > 0
         writer = self.kv.staged_write_prefill
 
-        def run(params, tokens, img_embeds, layers, write_spec, last_idx,
-                temps, top_ks, top_ps, seeds, steps):
+        def prefill(params, tokens, img_embeds, layers, write_spec, last_idx,
+                    temps, top_ks, top_ps, seeds, steps):
             batch = {"tokens": tokens}
             if has_img:
                 batch["img_embeds"] = img_embeds
@@ -531,7 +549,7 @@ class ServeEngine:
             toks = jnp.where(jnp.isfinite(rows).all(axis=-1), toks, -1)
             return toks, layers
 
-        return run
+        return prefill
 
     def _make_chunk(self):
         """One chunked-prefill device call: forward a stacked (n, C) round
@@ -887,6 +905,8 @@ class ServeEngine:
                 if shared is not None:
                     slot = free.pop(0)
                     self.queue.remove(req)
+                    self.spans.record("serve.queue", req.submitted_at,
+                                      time.perf_counter(), req.id)
                     plen = len(toks)
                     bucket = 1 << (plen - 1).bit_length()  # next power of two
                     bucket = min(bucket, self.S - self.img_len)
@@ -940,6 +960,8 @@ class ServeEngine:
                 if shared is not None:
                     slot = free.pop(0)
                     self.queue.remove(req)
+                    self.spans.record("serve.queue", req.submitted_at,
+                                      time.perf_counter(), req.id)
                     self.slot_req[slot] = req
                     self.active[slot] = False    # not decodable yet
                     self.kv.set_decode_shield(slot, True)
@@ -1090,7 +1112,8 @@ class ServeEngine:
             self.kv.update({**self.kv.state, "layers": new_layers})
             self.reg.counter("serve_prefill_chunks_total").inc(n)
             self.reg.counter("serve_prefill_dispatches_total").inc()
-            toks = np.asarray(toks)
+            with self._span("serve.prefill_wait"):
+                toks = np.asarray(toks)
             for j, (slot, st, req, ptoks, end, final, dest) in \
                     enumerate(group):
                 self.reg.counter("serve_prefill_tokens_total").inc(
@@ -1167,7 +1190,8 @@ class ServeEngine:
             jnp.asarray(temps), jnp.asarray(top_ks), jnp.asarray(top_ps),
             jnp.asarray(seeds), jnp.asarray(steps))
         self.kv.update({**self.kv.state, "layers": new_layers})
-        toks = np.asarray(toks)
+        with self._span("serve.prefill_wait"):
+            toks = np.asarray(toks)
         for j, (slot, req, _, _, ptoks) in enumerate(group):
             sp = req.sampling
             self.slot_req[slot] = req
@@ -1198,21 +1222,24 @@ class ServeEngine:
         re-verifies the allocator invariants, and — when an
         ``AlertManager`` is wired in — the serve-path light health checks
         and alert rules run every ``health_every`` iterations."""
-        if self.fault_plan is not None:
-            self._pending_faults.extend(self.fault_plan.events_at(self._iter))
-            if self._pending_faults:
-                self._apply_faults()
-        live = self._step()
-        if self.watchdog_iters:
-            self._watchdog()
-        if self.verify_cache and hasattr(self.kv, "verify"):
-            self.kv.verify()
-        if self.alerts is not None and self._iter % self.health_every == 0:
-            from repro.core.health import serve_light_checks
-            serve_light_checks(self)
-            self.alerts.evaluate()
-        self._iter += 1
-        return live
+        with self._span("serve.step"):
+            if self.fault_plan is not None:
+                self._pending_faults.extend(
+                    self.fault_plan.events_at(self._iter))
+                if self._pending_faults:
+                    self._apply_faults()
+            live = self._step()
+            if self.watchdog_iters:
+                self._watchdog()
+            if self.verify_cache and hasattr(self.kv, "verify"):
+                self.kv.verify()
+            if (self.alerts is not None
+                    and self._iter % self.health_every == 0):
+                from repro.core.health import serve_light_checks
+                serve_light_checks(self)
+                self.alerts.evaluate()
+            self._iter += 1
+            return live
 
     def _step(self):
         """One engine iteration: admit (+ up to one budget's worth of
@@ -1232,18 +1259,23 @@ class ServeEngine:
             # iteration, so sustained short-request traffic can slow a
             # mid-prefill long prompt but never starve it
             cls_spent: Dict[str, int] = {}
-            spent, stalled = self._run_prefill_chunks(self.budget,
-                                                      cls_spent=cls_spent)
-            self._admit()
+            with self._span("serve.prefill"):
+                spent, stalled = self._run_prefill_chunks(
+                    self.budget, cls_spent=cls_spent)
+            with self._span("serve.admit"):
+                self._admit()
             if spent < self.budget:
                 # leftover budget covers a fresh admission's first chunk in
                 # the same iteration (skip already-stalled slots: the pages
                 # they need did not appear mid-iteration; per-class caps
                 # carry over via cls_spent)
-                self._run_prefill_chunks(self.budget - spent, skip=stalled,
-                                         cls_spent=cls_spent)
+                with self._span("serve.prefill"):
+                    self._run_prefill_chunks(self.budget - spent,
+                                             skip=stalled,
+                                             cls_spent=cls_spent)
         else:
-            self._admit()
+            with self._span("serve.admit"):
+                self._admit()
         pf_tokens = self.reg.counter("serve_prefill_tokens_total").get() - pf0
         if streams_waiting and pf_tokens > (self.budget if self.chunk else 0):
             self.reg.counter("serve_decode_stall_iters").inc()
@@ -1252,34 +1284,45 @@ class ServeEngine:
         if not active_idx:
             # mid-prefill slots are still work in flight
             return bool(self.prefilling)
-        # per-slot sample-step index: the token being sampled now is
-        # out_tokens[len]+1 deep in the request's stream (the pending token,
-        # sampled earlier, is #len and gets emitted this iteration)
-        steps = np.zeros(self.B, np.int32)
-        for i in active_idx:
-            steps[i] = len(self.slot_req[i].out_tokens) + 1
-        # inactive slots decode at scratch position 0: their masked scatter
-        # lands in storage the next prefill rewrites (contiguous row 0) or
-        # in the scratch page (paged), never in live data
-        positions = np.where(self.active,
-                             np.minimum(self.slot_pos, self.S - 1), 0)
-        all_greedy = bool(np.all(self.temps[self.active] <= 0.0))
-        view = self.kv.decode_view()
-        poison = np.zeros(self.B, bool)
-        if self._poison_slots:
-            poison[sorted(self._poison_slots)] = True
-            self._poison_slots.clear()
-        sampled, new_layers = self._dispatch_fused(
-            self.params, jnp.asarray(self.next_token[:, None]),
-            view["layers"], view.get("page_table"),
-            jnp.asarray(positions), jnp.asarray(self.active),
-            jnp.asarray(self.temps), jnp.asarray(self.top_ks),
-            jnp.asarray(self.top_ps), jnp.asarray(self.seeds),
-            jnp.asarray(steps), jnp.asarray(poison), all_greedy)
-        self.kv.update({**view, "layers": new_layers})
+        with self._span("serve.decode_dispatch"):
+            # per-slot sample-step index: the token being sampled now is
+            # out_tokens[len]+1 deep in the request's stream (the pending
+            # token, sampled earlier, is #len and gets emitted this
+            # iteration)
+            steps = np.zeros(self.B, np.int32)
+            for i in active_idx:
+                steps[i] = len(self.slot_req[i].out_tokens) + 1
+            # inactive slots decode at scratch position 0: their masked
+            # scatter lands in storage the next prefill rewrites (contiguous
+            # row 0) or in the scratch page (paged), never in live data
+            positions = np.where(self.active,
+                                 np.minimum(self.slot_pos, self.S - 1), 0)
+            all_greedy = bool(np.all(self.temps[self.active] <= 0.0))
+            view = self.kv.decode_view()
+            poison = np.zeros(self.B, bool)
+            if self._poison_slots:
+                poison[sorted(self._poison_slots)] = True
+                self._poison_slots.clear()
+            sampled, new_layers = self._dispatch_fused(
+                self.params, jnp.asarray(self.next_token[:, None]),
+                view["layers"], view.get("page_table"),
+                jnp.asarray(positions), jnp.asarray(self.active),
+                jnp.asarray(self.temps), jnp.asarray(self.top_ks),
+                jnp.asarray(self.top_ps), jnp.asarray(self.seeds),
+                jnp.asarray(steps), jnp.asarray(poison), all_greedy)
+            self.kv.update({**view, "layers": new_layers})
         self.reg.counter("serve_decode_dispatches_total").inc()
         self.reg.counter("serve_iterations_total").inc()
-        sampled = np.asarray(sampled)     # the one (B,) host transfer
+        with self._span("serve.decode_wait"):
+            sampled = np.asarray(sampled)     # the one (B,) host transfer
+        with self._span("serve.emit"):
+            self._emit(active_idx, sampled)
+        return True
+
+    def _emit(self, active_idx: List[int], sampled: np.ndarray) -> None:
+        """Emit each decoding slot's pending token, finish and free the
+        requests that are done, and hold this step's ``sampled`` tokens as
+        the next pending ones (a ``-1`` sentinel recovers its slot)."""
         now = time.perf_counter()
         freed = False
         for i in active_idx:
@@ -1332,7 +1375,6 @@ class ServeEngine:
                 self.next_token[i] = sampled[i]
         if freed:
             self._export_memory()
-        return True
 
     def _export_memory(self):
         st = self.kv.memory_stats()
@@ -1344,16 +1386,6 @@ class ServeEngine:
         self.reg.gauge("serve_kv_bytes_reserved").set(st.bytes_reserved)
         self.reg.gauge("serve_kv_pages_shared").set(st.pages_shared)
         self.reg.gauge("serve_kv_bytes_per_chip").set(st.bytes_per_chip)
-        # per-step transient of the paged KV read path (byte math, one
-        # layer): the gather fallback scales with B·M·page, the pallas
-        # kernel with the page block only — dense rows gather nothing
-        transient = 0
-        if st.backend == "paged":
-            from repro.serve.kvcache import decode_transient_bytes
-            transient = decode_transient_bytes(
-                self.lm.cfg, self.B, self.kv.max_pages, st.page_size,
-                self.kv.dtype, self.kv.decode_impl, kv_dtype=st.kv_dtype)
-        self.reg.gauge("serve_decode_transient_bytes").set(transient)
         quant = st.kv_dtype == "int8"
         self.reg.gauge("serve_kv_quant_enabled").set(int(quant))
         self.reg.gauge("serve_kv_quant_scale_bytes").set(st.bytes_scales)

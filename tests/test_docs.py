@@ -20,6 +20,7 @@ ROOT = Path(__file__).resolve().parent.parent
 DOC = ROOT / "docs" / "telemetry.md"
 ROW = re.compile(r"^\|\s*`(serve_\w+)`\s*\|\s*(counter|gauge|histogram)\s*\|",
                  re.M)
+SPAN_ROW = re.compile(r"^\|\s*`(serve\.\w+)`\s*\|", re.M)
 
 
 def test_docs_suite_exists():
@@ -71,3 +72,27 @@ def test_telemetry_doc_matches_engine_registry():
     assert after == registered, (
         f"metrics registered only at runtime: "
         f"{sorted(set(after) - set(registered))}")
+
+
+def test_telemetry_doc_lists_every_span_the_engine_emits():
+    """The Spans table names exactly the spans a chunked engine and a
+    whole-prompt engine emit while serving a request to the end."""
+    doc = set(SPAN_ROW.findall(DOC.read_text()))
+    assert doc, "no span rows parsed from docs/telemetry.md"
+    cfg = dataclasses.replace(CONFIGS["llama3.2-3b"].reduced(),
+                              dtype="float32", num_layers=1)
+    lm = LM(cfg)
+    params = lm.init(jax.random.key(0))
+    emitted = set()
+    for chunk in (0, 2):
+        eng = ServeEngine(lm, params, max_batch=2, max_seq=16,
+                          cache_backend="paged", page_size=4,
+                          prefill_chunk=chunk)
+        eng.submit(Request(0, np.arange(5, dtype=np.int32) % cfg.vocab_size,
+                           max_new_tokens=2))
+        eng.run_until_drained()
+        emitted |= {s.name for s in eng.spans.spans()}
+    assert emitted == doc, (
+        f"docs/telemetry.md Spans table out of sync with the engine:\n"
+        f"  undocumented: {sorted(emitted - doc)}\n"
+        f"  stale doc rows: {sorted(doc - emitted)}")
