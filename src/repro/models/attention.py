@@ -257,13 +257,23 @@ def gather_pages(pool, page_table, positions=None):
     current batch, so transient decode memory grows with the (paged-enlarged)
     concurrent batch even though pinned memory does not.  The paged
     flash-decode kernel (``decode_attention(..., impl="pallas")``) walks the
-    table block-by-block instead and never materializes this view."""
+    table block-by-block instead and never materializes this view.
+
+    The gather clamps its ids (``mode="clip"``) and never changes one: every
+    id a table holds is in ``[0, P)`` — ``PagedCache`` writes only page ids
+    it allocated or the scratch page 0, and the dead-page redirect above
+    (and the off-chip redirect of the sharded twins, which gather through
+    here too) writes 0.  The default
+    ``mode="fill"`` would add an in-bounds mask and a NaN select over the
+    whole (B, M, page, KV, D) result, re-writing the gathered view every
+    layer for a fill that never fires.  Every pool and scale gather on the
+    paged read path uses the same mode."""
     b, m = page_table.shape
     page = pool.shape[1]
     if positions is not None:
         live = jnp.arange(m)[None, :] <= positions[:, None] // page  # (B, M)
         page_table = jnp.where(live, page_table, 0)
-    k = jnp.take(pool, page_table, axis=0)          # (B, M, page, KV, D)
+    k = jnp.take(pool, page_table, axis=0, mode="clip")  # (B, M, page, KV, D)
     return k.reshape(b, m * page, *pool.shape[2:])
 
 
@@ -272,7 +282,7 @@ def dequant_gathered(gathered, scale_pool, lt_or_table, b, rows, dtype):
     ``scale_pool`` (P, page, KV) fp32, ``lt_or_table`` the (B, M) table the
     int8 view was gathered through — the scales resolve through the *same*
     indirection, so row and scale can never come from different pages."""
-    sg = jnp.take(scale_pool, lt_or_table, axis=0).reshape(
+    sg = jnp.take(scale_pool, lt_or_table, axis=0, mode="clip").reshape(
         b, rows, scale_pool.shape[2])
     return (gathered.astype(jnp.float32) * sg[..., None]).astype(dtype)
 
@@ -306,8 +316,8 @@ def paged_gather_partials(q, k_pool, v_pool, page_table, positions,
     local = page_table - page_offset
     ok = live & (local >= 0) & (local < pn)
     lt = jnp.where(ok, local, 0)
-    kg = jnp.take(k_pool, lt, axis=0).reshape(b, m * page, *k_pool.shape[2:])
-    vg = jnp.take(v_pool, lt, axis=0).reshape(b, m * page, *v_pool.shape[2:])
+    kg = gather_pages(k_pool, lt)
+    vg = gather_pages(v_pool, lt)
     if k_scale is not None:
         kg = dequant_gathered(kg, k_scale, lt, b, m * page, jnp.float32)
         vg = dequant_gathered(vg, v_scale, lt, b, m * page, jnp.float32)
@@ -550,8 +560,8 @@ def paged_gather_chunk_partials(q, k_pool, v_pool, page_table, qpos,
     local = page_table - page_offset
     ok = live & (local >= 0) & (local < pn)
     lt = jnp.where(ok, local, 0)
-    kg = jnp.take(k_pool, lt, axis=0).reshape(b, m * page, *k_pool.shape[2:])
-    vg = jnp.take(v_pool, lt, axis=0).reshape(b, m * page, *v_pool.shape[2:])
+    kg = gather_pages(k_pool, lt)
+    vg = gather_pages(v_pool, lt)
     if k_scale is not None:
         kg = dequant_gathered(kg, k_scale, lt, b, m * page, jnp.float32)
         vg = dequant_gathered(vg, v_scale, lt, b, m * page, jnp.float32)

@@ -351,3 +351,149 @@ def test_decode_impl_rejected_values():
     with pytest.raises(AssertionError):
         lm.decode_step(params, toks, paged.decode_view(),
                        jnp.zeros(2, jnp.int32), decode_impl="typo")
+
+
+# ---------------------------------------------------------- clamped gather ----
+
+# distinct extents, so a (B, M, page, ...) shape names only the gathered
+# view (and its int8 scales) in the lowered module
+GB, GM, GPAGE, GKV, GG, GD, GP, GC = 3, 5, 4, 2, 3, 16, 11, 6
+
+
+def _gather_case(int8):
+    from repro.kernels.quant import quantize_kv
+    rng = np.random.default_rng(11)
+    kp, vp = (jnp.asarray(rng.normal(size=(GP, GPAGE, GKV, GD)), jnp.float32)
+              for _ in range(2))
+    ks = vs = None
+    if int8:
+        kp, ks = quantize_kv(kp)
+        vp, vs = quantize_kv(vp)
+    q = jnp.asarray(rng.normal(size=(GB, GC, GKV, GG, GD)), jnp.float32)
+    pt = jnp.asarray(rng.integers(0, GP, (GB, GM)), jnp.int32)
+    pos = jnp.asarray([0, 7, GM * GPAGE - 1], jnp.int32)
+    return q, kp, vp, ks, vs, pt, pos
+
+
+def _site(name):
+    from repro.models import attention as attn
+    off = jnp.int32(0)
+    qpos = lambda pos: pos[:, None] - jnp.arange(GC)[::-1][None, :]
+    return {
+        "gather_pages": lambda q, kp, vp, ks, vs, pt, pos:
+            attn.gather_pages(kp, pt, pos),
+        "decode_attention": lambda q, kp, vp, ks, vs, pt, pos:
+            attn.decode_attention(q[:, :1], kp, vp, pos, page_table=pt,
+                                  impl="gather", k_scale=ks, v_scale=vs),
+        "paged_gather_partials": lambda q, kp, vp, ks, vs, pt, pos:
+            attn.paged_gather_partials(q[:, :1], kp, vp, pt, pos, off,
+                                       k_scale=ks, v_scale=vs),
+        "paged_gather_chunk_partials": lambda q, kp, vp, ks, vs, pt, pos:
+            attn.paged_gather_chunk_partials(q, kp, vp, pt, qpos(pos), pos,
+                                             off, k_scale=ks, v_scale=vs),
+    }[name]
+
+
+@pytest.mark.parametrize("site,int8", [
+    ("gather_pages", False), ("decode_attention", False),
+    ("decode_attention", True), ("paged_gather_partials", False),
+    ("paged_gather_partials", True), ("paged_gather_chunk_partials", False),
+    ("paged_gather_chunk_partials", True)])
+def test_paged_gather_lowers_without_gathered_view_select(site, int8):
+    """Every pool and scale gather on the paged read path states its ids
+    are in range: the module holds the bare gather of the (B, M, page, ...)
+    view and no select of that shape (``jnp.take``'s default fill mode
+    re-writes the whole view through a mask and a NaN select).  The int32
+    (B, M) live-page ``where`` on the table is not such a select."""
+    import re
+    args = _gather_case(int8)
+    text = jax.jit(_site(site)).lower(*args).as_text()
+    view = re.compile(rf"tensor<{GB}x{GM}x{GPAGE}x[0-9x]*\w+>")
+    gathers = [ln for ln in text.splitlines()
+               if "stablehlo.gather" in ln and view.search(ln)]
+    assert gathers, text
+    if int8 and site != "gather_pages":
+        assert any(f"{GB}x{GM}x{GPAGE}x{GKV}xf32>" in ln
+                   for ln in gathers), text     # the scales' gather too
+    selects = [ln.strip() for ln in text.splitlines()
+               if "stablehlo.select" in ln and view.search(ln)]
+    assert not selects, selects
+
+
+@pytest.mark.parametrize("seed,chips", [(0, None), (1, 2), (2, None),
+                                        (3, 2)])
+def test_page_table_ids_stay_in_range_and_gather_is_unclamped(seed, chips):
+    """Seeded op streams over ``PagedCache`` — whole and chunked admissions
+    with prefix hits, extends, shields, frees and preemptive evictions:
+    after every op each table entry (host table and decode view) lies in
+    ``[0, num_pages)``, the assumption the clamped gather rests on.  On
+    those tables ``gather_pages`` (dead-page redirect on and off) is
+    bitwise the fill-mode ``jnp.take``, so the clamp never changed an id."""
+    from repro.models.attention import gather_pages
+    from repro.serve.kvcache import PagedCache
+
+    rng = np.random.default_rng(seed)
+    cfg = dataclasses.replace(CONFIGS["llama3.2-3b"].reduced(),
+                              dtype="float32", num_layers=1)
+    B, S, page = 4, 24, 4
+    kv = PagedCache(LM(cfg), B, S, dtype=jnp.float32, page_size=page,
+                    num_pages=14, locality_chips=chips)
+    # two slots' prompts repeat, so admissions hit shared prefix pages
+    prompts = [np.arange(16, dtype=np.int32), np.arange(16, dtype=np.int32),
+               np.arange(16, dtype=np.int32) + 3]
+    pool = jnp.asarray(rng.normal(size=(kv.P, page, 2, 8)), jnp.float32)
+    chunked = {}                        # slot -> [covered, length, prompt]
+    ops = ["alloc", "alloc_chunked", "extend", "extend", "free", "evict"]
+    seen = set()
+
+    def check():
+        for tbl in (kv.page_table, np.asarray(kv.decode_view()["page_table"])):
+            assert tbl.dtype == np.int32
+            assert 0 <= tbl.min() and tbl.max() < kv.P, tbl
+        pt = jnp.asarray(kv.page_table)
+        pos = jnp.asarray(rng.integers(0, S, B), jnp.int32)
+        live = jnp.arange(kv.max_pages)[None, :] <= pos[:, None] // page
+        fill = jnp.take(pool, jnp.where(live, pt, 0), axis=0, mode="fill")
+        np.testing.assert_array_equal(
+            np.asarray(gather_pages(pool, pt, pos)),
+            np.asarray(fill.reshape(B, -1, 2, 8)))
+        np.testing.assert_array_equal(
+            np.asarray(gather_pages(pool, pt)),
+            np.asarray(jnp.take(pool, pt, axis=0, mode="fill")
+                       .reshape(B, -1, 2, 8)))
+
+    for _ in range(50):
+        op, slot = ops[int(rng.integers(len(ops)))], int(rng.integers(B))
+        busy = bool(kv._slot_pages[slot])
+        prompt = prompts[int(rng.integers(len(prompts)))]
+        length = int(rng.integers(2, S + 1))
+        if op == "alloc" and not busy:
+            got = kv.alloc(slot, length, prefix=prompt[:length])
+            if got is not None:
+                seen.update(["alloc", "shared"] if got else ["alloc"])
+        elif op == "alloc_chunked" and not busy:
+            first = min(page, length)
+            if kv.alloc_chunked(slot, length, first,
+                                prefix=prompt[:length]) is not None:
+                kv.set_decode_shield(slot, True)
+                kv.register_landed(slot, prompt[:length], first)
+                chunked[slot] = [first, length, prompt[:length]]
+                seen.add("alloc_chunked")
+        elif op == "extend" and slot in chunked:
+            cov, total, pr = chunked[slot]
+            cover = min(cov + page, total)
+            if kv.extend(slot, cover):
+                kv.register_landed(slot, pr, cover)
+                chunked[slot][0] = cover
+                seen.add("extend")
+                if cover == total:
+                    kv.set_decode_shield(slot, False)
+                    del chunked[slot]
+        elif op in ("free", "evict") and busy:
+            (kv.free if op == "free" else kv.evict)(slot)
+            chunked.pop(slot, None)
+            seen.add(op)
+        check()
+    kv.verify()
+    assert {"alloc", "shared", "alloc_chunked", "extend", "free",
+            "evict"} <= seen, seen
