@@ -90,9 +90,15 @@ class Trace:
     def modules(self, jit_name: str, device: str = "0") -> List[float]:
         """Device durations (ns) of the executions of the jitted function
         ``jit_name``, in order, from the device's module line."""
+        return [b - a for a, b in self.module_spans(jit_name, device)]
+
+    def module_spans(self, jit_name: str, device: str = "0"
+                     ) -> List[Tuple[float, float]]:
+        """(start, end) in ns of each execution of ``jit_name`` that
+        starts inside the window, in order."""
         pat = re.compile(rf"^jit_{re.escape(jit_name)}(\b|[.(\[]|$)")
         lo, hi = self.window()
-        out = [d for line, name, t, d in self.devices[device]
+        out = [(t, t + d) for line, name, t, d in self.devices[device]
                if line == MODULES_LINE and pat.match(name) and lo <= t <= hi]
         if not out:
             names = sorted({n for ln, n, _, _ in self.devices[device]

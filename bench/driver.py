@@ -7,6 +7,11 @@ the new tokens of each request from ``Request.out_tokens``.  Every time is
 on the host clock, in seconds since the window opened: a request is timed
 from its due time, so a stall delays the requests behind it too.
 
+Requests a mix marks as due in set-up (its ``sessions``) are submitted by
+``prime`` before the window opens, and the engine is stepped until each
+has its first token: their prompts are in the cache when the window
+opens, and what set-up did stays on the window's clock at negative times.
+
 Per step the driver records what the step did, as the per-layer readers
 need it: the decode tokens and the contexts they attended, the prefill
 chunks and their positions, and (when ``pages`` is on) the pool's pages in
@@ -19,6 +24,8 @@ from dataclasses import dataclass, field
 from typing import List, Optional, Tuple
 
 from repro.serve import Request
+
+PRIME_STEPS = 100_000      # set-up steps before a session is given up on
 
 
 @dataclass
@@ -33,6 +40,7 @@ class Served:
     admitted: Optional[float] = None     # start of the step that admitted it
     token_times: List[float] = field(default_factory=list)
     prefilled: int = 0                   # prompt positions landed so far
+    setup: bool = False                  # submitted in set-up (a session)
 
     @property
     def first(self) -> Optional[float]:
@@ -59,8 +67,8 @@ class Window:
 
     def __init__(self, eng, due, pages: bool = False, span=None):
         self.eng = eng
-        self.served = [Served(d.id, d.due, len(d.prompt), d.max_new)
-                       for d in due]
+        self.served = [Served(d.id, d.due, len(d.prompt), d.max_new,
+                              setup=d.setup) for d in due]
         self._prompts = {d.id: d.prompt for d in due}
         self.steps: List[Step] = []
         self.pages = pages
@@ -129,14 +137,46 @@ class Window:
                 st.pages_total
         self.steps.append(rec)
 
+    def prime(self) -> None:
+        """Set-up: submit every request due in set-up and step the engine
+        until each has its first token.  None of these steps is the
+        window's; no-op for a mix with no such request."""
+        first = [s for s in self.served if s.setup]
+        if not first:
+            return
+        self.t0 = time.perf_counter()
+        for s in first:
+            self._submit(s)
+            s.due = s.submitted
+        while any(s.first is None and not s.done for s in first):
+            if len(self.steps) >= PRIME_STEPS:
+                raise RuntimeError(f"set-up: {PRIME_STEPS} steps and not "
+                                   f"every session has its first token")
+            self.step()
+        self.steps.clear()
+
+    def _reclock(self, t0: float) -> None:
+        """Put what was recorded since ``self.t0`` on a clock that starts
+        at ``t0``."""
+        dt = t0 - self.t0
+        for s in self.served:
+            if s.submitted is not None:
+                s.due -= dt
+                s.submitted -= dt
+                if s.admitted is not None:
+                    s.admitted -= dt
+                s.token_times = [t - dt for t in s.token_times]
+        self.t0 = t0
+
     def run(self, seconds: float, drain_s: float, on_step=None) -> None:
         """Open the window, drive it for ``seconds``, then step on for at
         most ``drain_s`` more until every request due in the window has
         its first token or has ended (none are submitted after the
         close)."""
-        pending = sorted(self.served, key=lambda s: s.due)
+        pending = sorted((s for s in self.served if s.submitted is None),
+                         key=lambda s: s.due)
         i = 0
-        self.t0 = time.perf_counter()
+        self._reclock(time.perf_counter())
         self.close = seconds
         while True:
             now = self.now()

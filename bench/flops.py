@@ -2,10 +2,12 @@
 shapes alone, and the chip's peaks to hold them against.
 
 "Needs" is the work of the step whatever path implements it: every weight
-read once, the KV of the live positions read, the new KV written, and the
-matrix products of the real tokens.  Padding, inactive slots and whatever
-a gather reads beyond the live positions are not counted, so a path that
-stops doing them shows as a gain in roofline share.
+read once, the cache of the live positions read, the new cache written,
+and the matrix products of the real tokens.  Padding, inactive slots and
+whatever a gather reads beyond the live positions are not counted, so a
+path that stops doing them shows as a gain in roofline share.  The counts
+are the architecture module's (``bench/arch/<model_type>.py``); the peaks
+are one chip's, and a reading over a cell's chips sums them (``summed``).
 """
 from __future__ import annotations
 
@@ -13,8 +15,11 @@ import json
 from pathlib import Path
 from typing import Any, Dict, Tuple
 
+import spec
+
 PEAKS = Path(__file__).resolve().parent / "peaks.json"
 DTYPE_BYTES = {"bfloat16": 2, "float16": 2, "float32": 4}
+PER_CHIP = ("bf16_flops_per_s", "hbm_bytes_per_s")
 
 
 def peaks(device_kind: str) -> Dict[str, Any]:
@@ -25,69 +30,47 @@ def peaks(device_kind: str) -> Dict[str, Any]:
     return table[device_kind]
 
 
-def _dims(c: Dict[str, Any]):
-    hd = c.get("head_dim") or c["hidden_size"] // c["num_attention_heads"]
-    return (c["num_hidden_layers"], c["hidden_size"],
-            c["num_attention_heads"], c["num_key_value_heads"], hd,
-            c["intermediate_size"], c["vocab_size"])
+def summed(chip: Dict[str, Any], chips: int) -> Dict[str, Any]:
+    """The peak rates of ``chips`` chips of one kind: work counted once,
+    against every chip the cell holds."""
+    return dict(chip, **{k: chip[k] * chips for k in PER_CHIP})
 
 
-def layer_params(c: Dict[str, Any]) -> int:
-    """Matrix parameters of one decoder layer (q, k, v, o, SwiGLU)."""
-    L, d, H, KV, hd, F, V = _dims(c)
-    return d * H * hd + 2 * d * KV * hd + H * hd * d + 3 * d * F
-
-
-def small_params(c: Dict[str, Any]) -> int:
-    """Norm scales and biases of the whole model."""
-    L, d, H, KV, hd, F, V = _dims(c)
-    qk = 2 * hd if c["model_type"] == "qwen3" else 0
-    attn_b = (H * hd + 2 * KV * hd + d) if c.get("attention_bias") else 0
-    mlp_b = (2 * F + d) if c.get("mlp_bias") else 0
-    return L * (2 * d + qk + attn_b + mlp_b) + d
-
-
-def weight_bytes(c: Dict[str, Any]) -> int:
-    """Bytes of weights one step reads: every layer, the norms, biases and
-    the output head (the tied embedding table is the head; an untied embedding
-    table is read only at the step's own rows, which is left out)."""
-    L, d, H, KV, hd, F, V = _dims(c)
-    n = L * layer_params(c) + small_params(c) + V * d
-    return n * DTYPE_BYTES[c["torch_dtype"]]
-
-
-def kv_bytes_per_position(c: Dict[str, Any]) -> int:
-    L, d, H, KV, hd, F, V = _dims(c)
-    return L * 2 * KV * hd * DTYPE_BYTES[c["torch_dtype"]]
-
-
-def attention_flops(c: Dict[str, Any], ctx: int) -> int:
-    """Scores and weighted sum of one query row over ``ctx`` positions,
-    all layers."""
-    L, d, H, KV, hd, F, V = _dims(c)
-    return 4 * L * H * hd * ctx
+def _arch(c: Dict[str, Any]):
+    return spec.arch(c["model_type"])
 
 
 def decode_step(c: Dict[str, Any], tokens: int, ctx_sum: int
                 ) -> Tuple[int, int]:
     """(FLOPs, bytes) one fused decode step needs for ``tokens`` live slots
     whose contexts (positions attended, the new one included) sum to
-    ``ctx_sum``: one row through every layer and the head per slot."""
-    L, d, H, KV, hd, F, V = _dims(c)
-    flops = 2 * (L * layer_params(c) + d * V) * tokens \
-        + attention_flops(c, ctx_sum)
-    kvb = kv_bytes_per_position(c)
-    return flops, weight_bytes(c) + kvb * ctx_sum + kvb * tokens
+    ``ctx_sum``."""
+    return _arch(c).decode_step(c, tokens, ctx_sum)
 
 
 def chunk(c: Dict[str, Any], start: int, n: int) -> Tuple[int, int]:
     """(FLOPs, bytes) a prefill chunk of ``n`` prompt tokens at positions
-    ``start .. start+n-1`` needs: every row through every layer, causal
-    attention over the prior positions and the chunk, and the head for
-    the last row only (the one the first token is sampled from)."""
-    L, d, H, KV, hd, F, V = _dims(c)
-    ctx = n * start + n * (n + 1) // 2       # sum of (p + 1) over the chunk
-    flops = 2 * L * layer_params(c) * n + 2 * d * V \
-        + attention_flops(c, ctx)
-    kvb = kv_bytes_per_position(c)
-    return flops, weight_bytes(c) + kvb * start + kvb * n
+    ``start .. start+n-1`` needs, the head for its last row only."""
+    return _arch(c).chunk(c, start, n)
+
+
+def weight_bytes(c: Dict[str, Any]) -> int:
+    """Bytes of weights one step reads: a decode step of no token."""
+    return decode_step(c, 0, 0)[1]
+
+
+def kv_bytes_per_position(c: Dict[str, Any]) -> int:
+    """Cache bytes of one position, no per-slot state."""
+    return _arch(c).cache_bytes(c, 1, 0)
+
+
+def layer_params(c: Dict[str, Any]) -> int:
+    """Matrix parameters of one layer, for an architecture whose layers
+    are all alike (its module's ``layer_params``)."""
+    return _arch(c).layer_params(c)
+
+
+def attention_flops(c: Dict[str, Any], ctx: int) -> int:
+    """Attention FLOPs of one query row over ``ctx`` positions, all layers
+    (its module's ``attention_flops``)."""
+    return _arch(c).attention_flops(c, ctx)

@@ -11,6 +11,7 @@ import math
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+import flops
 from driver import Step, Window
 from spec import Cell
 from devtrace import Trace
@@ -23,9 +24,14 @@ class Run:
     window: Window               # requests as served and steps as recorded
     setup_s: float               # process start to window open
     compile_s: float             # backend compile + cache loads in set-up
-    peaks: Dict[str, Any]        # bench/peaks.json row of this device
+    peaks: Dict[str, Any]        # bench/peaks.json row of this device,
+    #                              held summed over the cell's chips
     trace: Optional[Trace] = None
     traced: Tuple[int, int] = (0, 0)   # window.steps[a:b] ran under trace
+
+    def __post_init__(self):
+        # the readers hold the work against every chip of the cell
+        self.peaks = flops.summed(self.peaks, self.cell.chips)
 
     @property
     def config(self) -> Dict[str, Any]:
@@ -54,6 +60,6 @@ def token_gaps(run: Run) -> List[float]:
     inside the window, over all requests."""
     out: List[float] = []
     for s in run.window.served:
-        t = [x for x in s.token_times if x <= run.seconds]
+        t = [x for x in s.token_times if 0 <= x <= run.seconds]
         out += [b - a for a, b in zip(t, t[1:])]
     return out

@@ -7,7 +7,8 @@
 Set-up builds the cell's model and engine through the server's own
 builders (``repro.launch.serve``), with weights drawn from ``--seed`` on
 the device, and warms the two step programs the window uses (the prefill
-chunk and the fused decode) through the engine itself.  The window then
+chunk and the fused decode) through the engine itself; a mix's sessions
+are then submitted and prefilled to their first token.  The window then
 drives the cell's traffic open-loop for ``--seconds``.  Afterwards the
 engine is freed, the plain reference checks a seeded sample of the served
 tokens, and the last line of stdout is one JSON object::
@@ -104,7 +105,12 @@ def serve_args(engine: dict):
 
 
 def build(cell: spec.Cell, seed: int):
-    """(lm, params, engine) for the cell, through the server's builders."""
+    """(lm, params, engine) for the cell, through the server's builders.
+    The engine, and with it the cache pool, comes first and the weights
+    after: the pool of a mesh is made whole on its first chip and then
+    split, which does not fit beside the weights at a long-context pool's
+    size.  The engine is built without weights and given them before its
+    first dispatch."""
     import jax
     from jax.sharding import NamedSharding, PartitionSpec
     from repro.launch import serve
@@ -114,11 +120,12 @@ def build(cell: spec.Cell, seed: int):
     args = serve_args(cell.engine)
     mesh, dp_axis = serve.build_mesh(args)
     lm = LM(spec.program_config(cell.config))
+    eng = serve.build_engine(lm, None, args, mesh=mesh, dp_axis=dp_axis)
     params = weights.make_program_params(
         seed, cell.config, lm,
         None if mesh is None else NamedSharding(mesh, PartitionSpec()))
     jax.block_until_ready(params)
-    eng = serve.build_engine(lm, params, args, mesh=mesh, dp_axis=dp_axis)
+    eng.params = params
     return lm, params, eng
 
 
@@ -146,24 +153,36 @@ def wrap_spans(eng, span) -> None:
         setattr(eng, name, wrapped)
 
 
-def sample(window, seed: int):
+def sample(window, seed: int, rows: int):
     """The requests the reference checks: the one with the most served
-    tokens, then others drawn from the seed, until ``SAMPLE_TOKENS``."""
+    tokens, then others drawn from the seed, until ``SAMPLE_TOKENS``
+    checked, each on at most its last ``rows`` served tokens.  Requests
+    count once completed; a session (submitted in set-up) also while it
+    decodes, on the tokens it emitted by the close."""
     done = [s for s in window.served
-            if s.req is not None and s.req.status == "completed"]
+            if s.req is not None and (s.req.status == "completed"
+                                      or s.setup and s.req.out_tokens)]
     if not done:
         return []
     rng = np.random.default_rng([int(seed), 13])
     longest = max(done, key=lambda s: len(s.req.out_tokens))
     rest = [done[i] for i in rng.permutation(len(done))
             if done[i] is not longest]
-    picked, total = [longest], len(longest.req.out_tokens)
+    picked, total = [longest], min(len(longest.req.out_tokens), rows)
     for s in rest:
         if total >= SAMPLE_TOKENS or len(picked) >= SAMPLE_REQUESTS:
             break
         picked.append(s)
-        total += len(s.req.out_tokens)
+        total += min(len(s.req.out_tokens), rows)
     return picked
+
+
+def checked_part(req, rows: int):
+    """(prompt, served) for the reference: the request's last ``rows``
+    served tokens, after its prompt and the tokens before them."""
+    out = list(req.out_tokens)
+    return np.concatenate([np.asarray(req.prompt),
+                           np.asarray(out[:-rows], np.int32)]), out[-rows:]
 
 
 def check(cell: spec.Cell, seed: int, picked, control: bool = False
@@ -174,20 +193,21 @@ def check(cell: spec.Cell, seed: int, picked, control: bool = False
     token that the reference computed in float8 puts first."""
     import jax.numpy as jnp
     import reference
+    import traffic
     import weights
     w = weights.make_canonical(seed, cell.config)
     seq = int(cell.engine["max_seq"])
-    rows = int(cell.traffic["output"]["max"])
+    rows = traffic.checked(cell.traffic)
     worst, tokens, ctrl = 0.0, 0, 0.0
     for s in picked:
+        prompt, out = checked_part(s.req, rows)
         gap, _, logits = reference.teacher_forced(
-            w, cell.config, s.req.prompt, s.req.out_tokens, seq, rows)
+            w, cell.config, prompt, out, seq, rows)
         worst = max(worst, float(gap.max()))
         tokens += len(gap)
         if control:
             _, top8, _ = reference.teacher_forced(
-                w, cell.config, s.req.prompt, s.req.out_tokens, seq, rows,
-                mode="fp8")
+                w, cell.config, prompt, out, seq, rows, mode="fp8")
             pick = jnp.take_along_axis(logits, jnp.asarray(top8)[:, None],
                                        axis=1)[:, 0]
             ctrl = max(ctrl, float((logits.max(axis=1) - pick).max()))
@@ -220,8 +240,10 @@ def outcomes(inw, mix: dict, seconds: float):
     """(owed, failed) among the requests submitted in the window.  Owed:
     in an open loop each one due in the window, which must have started
     answering by the drain's end; in a queue due at once, each one
-    admitted before the close.  Failed: owed and no first token, or ended
-    other than completed.  One still decoding is late, not failed."""
+    admitted before the close; of sessions, every session (each has its
+    first token before the window opens).  Failed: owed and no first
+    token, or ended other than completed.  One still decoding is late, not
+    failed; a session decodes through the window by design."""
     def ended_badly(s):
         return s.req.status not in ("pending", "completed")
 
@@ -287,13 +309,15 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool,
     with CompileClock() as clock:
         lm, params, eng = build(cell, seed)
         warm(eng, cell.config["vocab_size"])
+        cap, marks = Capture(), {}
+        if traced:
+            wrap_spans(eng, span)
+            cap.warm()
+        win = driver.Window(eng, due, pages=traced,
+                            span=span if traced else None)
+        # a mix's sessions prefill and take their first token in set-up
+        win.prime()
     compile_s = clock.seconds
-    cap, marks = Capture(), {}
-    if traced:
-        wrap_spans(eng, span)
-        cap.warm()
-    win = driver.Window(eng, due, pages=traced,
-                        span=span if traced else None)
     # the profiler stops only after the drain: stopping writes the trace,
     # which takes seconds that would stall the loop inside the window
     t_a = seconds - min(TRACE_SECONDS, seconds / 2)
@@ -340,7 +364,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool,
     owed, failed = outcomes(inw, cell.traffic, seconds)
     late = [s.submitted - s.due for s in inw]
 
-    picked = sample(win, seed)
+    picked = sample(win, seed, traffic.checked(cell.traffic))
     eng = params = win.eng = None
     gc.collect()
     t_ref = time.perf_counter()

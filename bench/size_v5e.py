@@ -7,17 +7,18 @@ shapes for a described TPU v5e, without a chip, and print
     JAX_PLATFORMS=cpu PYTHONPATH=src python3 bench/size_v5e.py \\
         --workload qwen3-4b.chat [--num-pages 2048]
     JAX_PLATFORMS=cpu PYTHONPATH=src python3 bench/size_v5e.py \\
-        --config qwen3-4b --engine '{"max_batch": 8, "max_seq": 12288,
-        "page_size": 16, "num_pages": 6144, "prefill_chunk": 512,
+        --config qwen3-4b --engine '{"max_batch": 8, "max_seq": 4096,
+        "page_size": 16, "num_pages": 2052, "prefill_chunk": 512,
         "mesh": "4"}'           # a cell not in BENCHMARK.json yet
 
-The engine is built on the CPU with a pool of a few pages; the programs
-are lowered with the parameters and the pool as shapes placed on the
-described chip (or, with ``"mesh"``, on a described 2x2 mesh, the pool
-sharded as the engine shards it), the pool at ``--num-pages`` (default:
-the cell's).  Memory is reported per chip.  Like
-``tools/compile_v5e.py``, nothing runs: a compile that passes is not a
-chip run.
+The engine is built on the CPU with a pool of a few pages and the
+configuration cut to one layer (its architecture module's ``one_layer``,
+``bench/arch/<model_type>.py``); the programs are lowered with the
+parameters and the pool as shapes placed on the described chip (or, with
+``"mesh"``, on a described 2x2 mesh, the pool sharded as the engine
+shards it), the pool at ``--num-pages`` (default: the cell's).  Memory is
+reported per chip.  Like ``tools/compile_v5e.py``, nothing runs: a
+compile that passes is not a chip run.
 """
 from __future__ import annotations
 
@@ -83,8 +84,9 @@ def main(argv=None) -> None:
         return real_put(x, sharding, **kw)
 
     jax.device_put = put
+    arch = spec.arch(config["model_type"])
     lm = LM(spec.program_config(config))
-    one = dict(config, num_hidden_layers=1)
+    one = arch.one_layer(config)
     small = jax.jit(lambda k: weights.to_program(
         weights.canonical(k, one), one, lm.cfg.padded_vocab))(
         weights.seed_key(0))
@@ -110,10 +112,10 @@ def main(argv=None) -> None:
         params, sds((1, C), i32), layers, sds((1, M), i32), sds((1, C), i32),
         sds((1,), i32), sds((1,), i32), sds((1,), f32), sds((1,), i32),
         sds((1,), f32), sds((1,), i32), sds((1,), i32))
-    pool = pages * flops.kv_bytes_per_position(config) \
-        * int(engine["page_size"])
+    pool = arch.cache_bytes(config, pages * int(engine["page_size"]),
+                            int(engine["max_batch"]))
     chips = 1 if mesh is None else mesh.size
-    print(f"{name}: {pages} pages = {pool} B of KV pool over {chips} "
+    print(f"{name}: {pages} pages = {pool} B of cache over {chips} "
           f"chip(s)")
     worst = 0
     for name, low in (("decode", fused), ("chunk", chunk)):

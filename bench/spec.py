@@ -9,39 +9,28 @@ configuration and a traffic mix; the harness finds
 * ``bench/cells/<cell>.json``      the engine settings of the cell (slots,
   positions, pool, chunk) and the limits of its correctness check;
 * ``bench/e2e/<metric>.py`` and ``bench/metrics/<metric>.py``, one reader
-  per end-to-end and per-layer metric.
+  per end-to-end and per-layer metric;
+* ``bench/arch/<model_type>.py``   one module per architecture: the
+  program's fields, the canonical weights, the plain forward and the work
+  counts of a configuration whose ``model_type`` it is named after.
 
-Nothing here imports JAX, so the tests of the data files run anywhere.
+Nothing here imports JAX, so the tests of the data files run anywhere; an
+architecture module does, once it is first asked for.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 from dataclasses import dataclass
 from pathlib import Path
+from types import ModuleType
 from typing import Any, Callable, Dict, List
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
-
-# the source's config keys, as the program's ModelConfig names them
-CONFIG_KEYS = {
-    "hidden_size": "d_model",
-    "intermediate_size": "d_ff",
-    "num_hidden_layers": "num_layers",
-    "num_attention_heads": "num_heads",
-    "num_key_value_heads": "num_kv_heads",
-    "head_dim": "head_dim",
-    "vocab_size": "vocab_size",
-    "rms_norm_eps": "norm_eps",
-    "rope_theta": "rope_theta",
-    "tie_word_embeddings": "tie_embeddings",
-    "attention_bias": "use_bias",
-    "hidden_act": "act",
-}
-# what a model_type implies that its config.json does not spell out
-MODEL_TYPES = {"qwen3": {"qk_norm": True}, "llama": {"qk_norm": False}}
+ARCH = BENCH / "arch"
 
 
 def load_json(path: Path) -> Any:
@@ -94,14 +83,9 @@ def load_cell(name: str) -> Cell:
 
 
 def model_fields(config: Dict[str, Any]) -> Dict[str, Any]:
-    """The program's ModelConfig fields for a configuration file."""
-    out = {CONFIG_KEYS[k]: v for k, v in config.items() if k in CONFIG_KEYS}
-    # the program has one switch for the biases of attention and of the MLP
-    if bool(config.get("mlp_bias")) != bool(config["attention_bias"]):
-        raise SystemExit("the program cannot run attention_bias "
-                         f"{config['attention_bias']} with mlp_bias "
-                         f"{config.get('mlp_bias')}")
-    out.update(MODEL_TYPES[config["model_type"]])
+    """The program's ModelConfig fields for a configuration file: its
+    architecture module's, and the served dtype."""
+    out = arch(config["model_type"]).model_fields(config)
     out["dtype"] = out["param_dtype"] = config["torch_dtype"]
     return out
 
@@ -112,6 +96,25 @@ def program_config(config: Dict[str, Any]):
     from repro.configs import get_config
     base = get_config(config["arch"])
     return dataclasses.replace(base, **model_fields(config))
+
+
+def arch(model_type: str) -> ModuleType:
+    """The module of ``bench/arch/<model_type>.py``; a model type without
+    one is an error that names the file to add."""
+    path = ARCH / f"{model_type}.py"
+    if not path.exists():
+        raise SystemExit(f"no architecture module for model_type "
+                         f"{model_type!r}: add bench/arch/{model_type}.py "
+                         f"(looked for {path})")
+    return _load(path, f"bench_arch_{model_type}")
+
+
+@functools.lru_cache(maxsize=None)
+def _load(path: Path, name: str) -> ModuleType:
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def reader(kind: str, metric: str) -> Callable:

@@ -1,71 +1,38 @@
 """Weights drawn from the run's seed, in one jitted call on the device.
 
-The benchmark, not the program, defines the weights: a canonical tree of
-a dense GQA decoder (the names and orientation of the published
-checkpoints), drawn from ``--seed`` in the dtype the model is served in.
+The benchmark, not the program, defines the weights: a canonical tree
+(the names and orientation of the published checkpoints), whose leaves the
+configuration's architecture module (``bench/arch/<model_type>.py``)
+lists, drawn from ``--seed`` in the dtype the model is served in.
 ``to_program`` lays that tree out as the program's parameter tree, and the
 plain reference (``reference.py``) draws the same canonical tree again
 from the seed, so it takes nothing the program made.
 
 Every leaf is ``std * N(0, 1)``; norm scales are ``1 + 0.05 * N(0, 1)`` so
-that a norm whose scale is dropped or misplaced shows in the logits, and
-biases (where ``attention_bias`` / ``mlp_bias`` are on) are
-``BIAS_STD * N(0, 1)``, small beside the activations they are added to.
+that a norm whose scale is dropped or misplaced shows in the logits.  The
+modules draw matrices at ``STD`` (read when called, so a test can change
+it) and biases at ``BIAS_STD``, small beside the activations they are
+added to.  Leaf ``i`` of the sorted names is drawn with ``fold_in(key,
+i)``: renaming a leaf changes every weight.
 """
 from __future__ import annotations
 
-import math
 from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+import spec
+
 STD = 0.02
 BIAS_STD = 0.05
 
 
-def sizes(config: Dict[str, Any]) -> Dict[str, int]:
-    c = config
-    return dict(L=c["num_hidden_layers"], d=c["hidden_size"],
-                H=c["num_attention_heads"], KV=c["num_key_value_heads"],
-                hd=c.get("head_dim") or c["hidden_size"]
-                // c["num_attention_heads"],
-                F=c["intermediate_size"], V=c["vocab_size"])
-
-
 def shapes(config: Dict[str, Any]) -> Dict[str, tuple]:
-    """Canonical leaf -> (shape, std); std None marks a norm scale."""
-    s = sizes(config)
-    L, d, H, KV, hd, F, V = (s[k] for k in ("L", "d", "H", "KV", "hd", "F",
-                                            "V"))
-    out_std = STD / math.sqrt(2 * L)
-    leaves = {
-        "embed": ((V, d), STD),
-        "final_norm": ((d,), None),
-        "ln1": ((L, d), None),
-        "ln2": ((L, d), None),
-        "wq": ((L, d, H * hd), STD),
-        "wk": ((L, d, KV * hd), STD),
-        "wv": ((L, d, KV * hd), STD),
-        "wo": ((L, H * hd, d), out_std),
-        "w_gate": ((L, d, F), STD),
-        "w_up": ((L, d, F), STD),
-        "w_down": ((L, F, d), out_std),
-    }
-    if config.get("attention_bias"):
-        for name, width in (("bq", H * hd), ("bk", KV * hd), ("bv", KV * hd),
-                            ("bo", d)):
-            leaves[name] = ((L, width), BIAS_STD)
-    if config.get("mlp_bias"):
-        for name, width in (("b_gate", F), ("b_up", F), ("b_down", d)):
-            leaves[name] = ((L, width), BIAS_STD)
-    if config["model_type"] == "qwen3":
-        leaves["q_norm"] = ((L, hd), None)
-        leaves["k_norm"] = ((L, hd), None)
-    if not config["tie_word_embeddings"]:
-        leaves["lm_head"] = ((d, V), STD)
-    return leaves
+    """Canonical leaf -> (shape, std); std None marks a norm scale.  The
+    leaves are the architecture module's (``bench/arch/<model_type>.py``)."""
+    return spec.arch(config["model_type"]).leaves(config)
 
 
 def seed_key(seed: int):
@@ -92,39 +59,10 @@ def make_canonical(seed: int, config: Dict[str, Any]):
 
 def to_program(w: Dict[str, jax.Array], config: Dict[str, Any],
                padded_vocab: int) -> Dict[str, Any]:
-    """The program's parameter tree (``repro.models.transformer``'s spec)
-    holding the canonical weights ``w``."""
-    s = sizes(config)
-    L, d, H, KV, hd = s["L"], s["d"], s["H"], s["KV"], s["hd"]
-    pad = padded_vocab - s["V"]
-    attn = {"wq": {"kernel": w["wq"].reshape(L, d, H, hd)},
-            "wk": {"kernel": w["wk"].reshape(L, d, KV, hd)},
-            "wv": {"kernel": w["wv"].reshape(L, d, KV, hd)},
-            "wo": {"kernel": w["wo"].reshape(L, H, hd, d)}}
-    if "bq" in w:
-        attn["wq"]["bias"] = w["bq"].reshape(L, H, hd)
-        attn["wk"]["bias"] = w["bk"].reshape(L, KV, hd)
-        attn["wv"]["bias"] = w["bv"].reshape(L, KV, hd)
-        attn["wo"]["bias"] = w["bo"]
-    mlp = {"wg": {"kernel": w["w_gate"]}, "wi": {"kernel": w["w_up"]},
-           "wo": {"kernel": w["w_down"]}}
-    if "b_gate" in w:
-        mlp["wg"]["bias"], mlp["wi"]["bias"], mlp["wo"]["bias"] = \
-            w["b_gate"], w["b_up"], w["b_down"]
-    if "q_norm" in w:
-        attn["q_norm"] = {"scale": w["q_norm"]}
-        attn["k_norm"] = {"scale": w["k_norm"]}
-    tree = {
-        "embed": {"table": jnp.pad(w["embed"], ((0, pad), (0, 0)))},
-        "final_norm": {"scale": w["final_norm"]},
-        "layers": {"ln1": {"scale": w["ln1"]}, "attn": attn,
-                   "ln2": {"scale": w["ln2"]},
-                   # SwiGLU as the program writes it: act(wg) * wi, then wo
-                   "mlp": mlp},
-    }
-    if "lm_head" in w:
-        tree["lm_head"] = {"kernel": jnp.pad(w["lm_head"], ((0, 0), (0, pad)))}
-    return tree
+    """The program's parameter tree holding the canonical weights ``w``,
+    as the architecture module lays it out."""
+    return spec.arch(config["model_type"]).to_program(w, config,
+                                                      padded_vocab)
 
 
 def make_program_params(seed: int, config: Dict[str, Any], lm, sharding=None):
@@ -140,8 +78,8 @@ def make_program_params(seed: int, config: Dict[str, Any], lm, sharding=None):
                        seed_key(0)))
     if want != got:
         raise SystemExit(f"the program's parameter tree is not the one "
-                         f"bench/weights.py lays out:\n  program {want}\n"
-                         f"  bench   {got}")
+                         f"bench/arch/{config['model_type']}.py lays out:"
+                         f"\n  program {want}\n  bench   {got}")
     fn = jax.jit(lambda key: to_program(canonical(key, config), config, pv),
                  out_shardings=sharding)
     return fn(seed_key(seed))
